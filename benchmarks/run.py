@@ -55,6 +55,7 @@ FLOPs/bytes cost attribution from the global ProgramCache).
 import argparse
 import functools
 import json
+import os
 import sys
 
 
@@ -83,6 +84,9 @@ def main() -> None:
     ap.add_argument("--precision-json", default="BENCH_precision.json",
                     help="where to persist the mixed-precision rows")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
     from . import (bench_accuracy, bench_compile, bench_decode,
                    bench_depth_particles, bench_dispatch, bench_kernels,
                    bench_lifecycle, bench_obs, bench_precision,
@@ -110,62 +114,55 @@ def main() -> None:
             print(f"# --- {name} ---", flush=True)
             fn()
     if "scaling" in only:
-        import jax
         rows = [r for r in util.ROWS if r["name"].startswith("scaling/")]
         with open(args.scaling_json, "w") as f:
-            json.dump({"devices": len(jax.devices()),
+            json.dump({**util.device_info(),
                        "backend": args.scaling_backend,
                        "model_axis": args.scaling_model,
                        "rows": rows, "obs": util.obs_context()}, f, indent=1)
         print(f"# wrote {len(rows)} scaling rows -> {args.scaling_json}",
               flush=True)
     if "serve" in only:
-        import jax
         rows = [r for r in util.ROWS if r["name"].startswith("serve/")]
         with open(args.serve_json, "w") as f:
-            json.dump({"devices": len(jax.devices()), "rows": rows,
+            json.dump({**util.device_info(), "rows": rows,
                        "obs": util.obs_context()}, f, indent=1)
         print(f"# wrote {len(rows)} serve rows -> {args.serve_json}",
               flush=True)
     if "compile" in only:
-        import jax
         from repro.runtime import global_cache
         rows = [r for r in util.ROWS if r["name"].startswith("compile/")]
         with open(args.runtime_json, "w") as f:
-            json.dump({"devices": len(jax.devices()),
+            json.dump({**util.device_info(),
                        "cache": global_cache().snapshot_stats(),
                        "rows": rows, "obs": util.obs_context()}, f, indent=1)
         print(f"# wrote {len(rows)} compile rows -> {args.runtime_json}",
               flush=True)
     if "lifecycle" in only:
-        import jax
         rows = [r for r in util.ROWS if r["name"].startswith("lifecycle/")]
         with open(args.lifecycle_json, "w") as f:
-            json.dump({"devices": len(jax.devices()), "rows": rows,
+            json.dump({**util.device_info(), "rows": rows,
                        "obs": util.obs_context()}, f, indent=1)
         print(f"# wrote {len(rows)} lifecycle rows -> {args.lifecycle_json}",
               flush=True)
     if "decode" in only:
-        import jax
         rows = [r for r in util.ROWS if r["name"].startswith("decode/")]
         with open(args.decode_json, "w") as f:
-            json.dump({"devices": len(jax.devices()), "rows": rows,
+            json.dump({**util.device_info(), "rows": rows,
                        "obs": util.obs_context()}, f, indent=1)
         print(f"# wrote {len(rows)} decode rows -> {args.decode_json}",
               flush=True)
     if "obs" in only:
-        import jax
         rows = [r for r in util.ROWS if r["name"].startswith("obs/")]
         with open(args.obs_json, "w") as f:
-            json.dump({"devices": len(jax.devices()), "rows": rows,
+            json.dump({**util.device_info(), "rows": rows,
                        "obs": util.obs_context()}, f, indent=1)
         print(f"# wrote {len(rows)} obs rows -> {args.obs_json}",
               flush=True)
     if "precision" in only:
-        import jax
         rows = [r for r in util.ROWS if r["name"].startswith("precision/")]
         with open(args.precision_json, "w") as f:
-            json.dump({"devices": len(jax.devices()), "rows": rows,
+            json.dump({**util.device_info(), "rows": rows,
                        "obs": util.obs_context()}, f, indent=1)
         print(f"# wrote {len(rows)} precision rows -> {args.precision_json}",
               flush=True)

@@ -45,6 +45,13 @@ def emit(name: str, us: float, derived: str = ""):
     print(f"{name},{us:.1f},{derived}", flush=True)
 
 
+def device_info() -> dict:
+    """The device every BENCH_*.json names: platform, kind and count."""
+    d = jax.devices()[0]
+    return {"platform": d.platform, "device_kind": d.device_kind,
+            "devices": len(jax.devices())}
+
+
 def obs_context() -> dict:
     """Observability context attached to every BENCH_*.json artifact:
     tracer/registry state plus per-program cost attribution for whatever

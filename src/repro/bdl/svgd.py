@@ -249,10 +249,16 @@ def _svgd_leader(particle, lr, lengthscale, dataloader, epochs):
             views = {pid: f.wait() for pid, f in views.items()}
 
             flat, unravel = ravel_pytree(particle.state["params"])
-            theta = [flat] + [ravel_pytree(views[pid].parameters())[0]
+
+            def here(x, home=flat.sharding):
+                # followers may live on other devices: gather to the leader
+                return x if x.sharding == home else jax.device_put(x, home)
+
+            theta = [flat] + [here(ravel_pytree(views[pid].parameters())[0])
                               for pid in others]
             gflat = [ravel_pytree(particle.state["grads"])[0]] + \
-                    [ravel_pytree(views[pid].gradients())[0] for pid in others]
+                    [here(ravel_pytree(views[pid].gradients())[0])
+                     for pid in others]
             theta = jnp.stack(theta).astype(jnp.float32)
             g = jnp.stack(gflat).astype(jnp.float32)
 

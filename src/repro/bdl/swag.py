@@ -236,14 +236,21 @@ class MultiSWAG(Infer):
         moments kernel with its platform gating (``use_kernel=True``)."""
         if samples_per_particle <= 0:
             return super().posterior_predictive(**kw)
+        from ..runtime import jit_program
         rng = jax.random.PRNGKey(0) if rng is None else rng
         # dense live rows (not the capacity-padded canonical form): a
         # padding slot's zero moments must never be sampled as a member
-        stacked_swag = self.store.dense("swag")
-        sampled = swag_sample_stacked(stacked_swag, rng,
-                                      samples_per_particle, scale,
-                                      use_kernel=use_kernel)
-        return self.push_dist.serve(params=sampled, **kw)
+        args = (self.store.dense("swag"), rng)
+        # one cached program for the whole draw (not one eager dispatch
+        # per op and leaf)
+        sample = jit_program(
+            "swag_sample",
+            ("swag_sample", samples_per_particle, float(scale),
+             bool(use_kernel)),
+            lambda st, key: swag_sample_stacked(
+                st, key, samples_per_particle, scale, use_kernel=use_kernel),
+            args)
+        return self.push_dist.serve(params=sample(*args), **kw)
 
     def sample_predict(self, batch, *, samples_per_particle: int = 5,
                        rng=None, scale: float = 1.0):

@@ -211,9 +211,14 @@ class Particle:
         """theta <- theta - lr * update (SVGD follow; paper Fig. 6)."""
 
         def do(_self):
+            # the update may come from a leader on another device
+            def upd(p, u):
+                if u.sharding != p.sharding:
+                    u = jax.device_put(u, p.sharding)
+                return p - lr * u.astype(p.dtype)
+
             _self.state["params"] = jax.tree.map(
-                lambda p, u: p - lr * u.astype(p.dtype),
-                _self.state["params"], update)
+                upd, _self.state["params"], update)
             return None
 
         return self.nel.dispatch(self.pid, do, self, needs_device=True)

@@ -615,6 +615,10 @@ class ParticleStore:
             else:
                 pad = jax.tree.map(jnp.zeros_like, template)
                 st = _stack_rows([rows.get(s, pad) for s in range(cap)])
+                # the stack now holds every row: drop the cached row copies
+                # instead of keeping the key twice on device (reads slice
+                # them back lazily)
+                self._rows.pop(key, None)
             self.stats["stacks"] += 1
         st = self._place(st)
         self._stacked[key] = st
@@ -664,9 +668,15 @@ class ParticleStore:
         replicate rows host-side (SWAG serve-time sampling, checkpoint)."""
         with self._lock:
             pids = self.pids if pids is None else list(pids)
-            rows = [self.read(key, p) for p in pids]
             self.stats["stacks"] += 1
-            return _stack_rows(rows)
+            st = self._stacked.get(key)
+            if st is not None and not self._dirty.get(key) \
+                    and _leading_or_none(st) == self.capacity:
+                # one gather out of the canonical stack: no per-row copies
+                # cached in the store on the way
+                idx = jnp.asarray([self._slot_of[p] for p in pids])
+                return jax.tree.map(lambda x: x[idx], st)
+            return _stack_rows([self.read(key, p) for p in pids])
 
     def checkout(self, key: str, pids: Optional[Sequence[int]] = None):
         """Like ``stacked`` but transfers buffer ownership to the caller:

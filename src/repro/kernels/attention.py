@@ -121,6 +121,7 @@ def flash_attention(q, k, v, *, causal: bool = True, q_block: int = 128,
             pltpu.VMEM((G * q_block, hd), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qr, kr, vr)
     out = out.reshape(B, KVH, G, S_p, hd).transpose(0, 3, 1, 2, 4)
     return out.reshape(B, S_p, H, hd)[:, :S]

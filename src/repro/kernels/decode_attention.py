@@ -40,6 +40,8 @@ def _decode_kernel(q_ref, k_ref, v_ref, pos_ref, o_ref, m_scr, l_scr, acc_scr,
     # reads (undefined contents) — mask them by index, not by pos.
     col = ci * c_block + jax.lax.broadcasted_iota(jnp.int32, (1, c_block), 1)
     valid = (pos >= 0) & (col < c_len)
+    # the same tail bound along the sublane axis, for the (cb, hd) v tile
+    row = ci * c_block + jax.lax.broadcasted_iota(jnp.int32, (c_block, 1), 0)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (G, cb)
     s = jnp.where(valid, s, NEG_INF)
@@ -48,8 +50,10 @@ def _decode_kernel(q_ref, k_ref, v_ref, pos_ref, o_ref, m_scr, l_scr, acc_scr,
     p = jnp.exp(s - m_new)
     # Zero masked weights explicitly: on an all-masked tile exp(0)=1, and
     # 0 * (undefined v) would still poison the accumulator with NaNs.
+    # Empty slots (pos < 0) hold finite cache contents, so only the
+    # out-of-bounds tail needs its v rows zeroed.
     p = jnp.where(valid, p, 0.0)
-    v = jnp.where(valid.reshape(-1, 1), v, 0.0)
+    v = jnp.where(row < c_len, v, 0.0)
     corr = jnp.exp(m_prev - m_new)
     l_new = l_prev * corr + p.sum(axis=1, keepdims=True)
     pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
@@ -78,7 +82,9 @@ def decode_attention(q, k_cache, v_cache, k_pos, *, c_block: int = 512,
     qr = q.reshape(B, KVH, G, hd).reshape(B * KVH, G, hd)
     kr = k_cache.transpose(0, 2, 1, 3).reshape(B * KVH, C, hd)
     vr = v_cache.transpose(0, 2, 1, 3).reshape(B * KVH, C, hd)
-    pr = jnp.repeat(k_pos, KVH, axis=0)                    # (B*KVH, C)
+    # (B*KVH, 1, C): the unit sublane dim makes the (1, c_block) pos tile
+    # a full-extent x lane-aligned block, which the TPU lowering accepts
+    pr = jnp.repeat(k_pos, KVH, axis=0)[:, None, :]
 
     kernel = functools.partial(_decode_kernel, scale=scale, n_cb=n_cb,
                                c_block=c_block, c_len=C)
@@ -89,7 +95,7 @@ def decode_attention(q, k_cache, v_cache, k_pos, *, c_block: int = 512,
             pl.BlockSpec((1, G, hd), lambda b, ci: (b, 0, 0)),
             pl.BlockSpec((1, c_block, hd), lambda b, ci: (b, ci, 0)),
             pl.BlockSpec((1, c_block, hd), lambda b, ci: (b, ci, 0)),
-            pl.BlockSpec((1, c_block), lambda b, ci: (b, ci)),
+            pl.BlockSpec((1, 1, c_block), lambda b, ci: (b, 0, ci)),
         ],
         out_specs=pl.BlockSpec((1, G, hd), lambda b, ci: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B * KVH, G, hd), q.dtype),
@@ -99,5 +105,6 @@ def decode_attention(q, k_cache, v_cache, k_pos, *, c_block: int = 512,
             pltpu.VMEM((G, hd), jnp.float32),
         ],
         interpret=interpret,
+        name="decode_attention",
     )(qr, kr, vr, pr)
     return out.reshape(B, KVH, G, hd).reshape(B, 1, H, hd)

@@ -55,6 +55,11 @@ def _paged_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref,
     col = pi * page_size + jax.lax.broadcasted_iota(
         jnp.int32, (1, page_size), 1)
     valid = (col <= sl) & (sl >= 0)
+    # the same bound along the sublane axis, for the (ps, hd) v tile
+    # (built from its own iota: a lane-to-sublane reshape does not lower)
+    row = pi * page_size + jax.lax.broadcasted_iota(
+        jnp.int32, (page_size, 1), 0)
+    v_valid = (row <= sl) & (sl >= 0)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (G, ps)
     s = jnp.where(valid, s, NEG_INF)
@@ -64,7 +69,7 @@ def _paged_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref,
     # Zero masked weights: on an all-masked page exp(0)=1, and the slots
     # past the sequence tail hold stale writes from a previous owner.
     p = jnp.where(valid, p, 0.0)
-    v = jnp.where(valid.reshape(-1, 1), v, 0.0)
+    v = jnp.where(v_valid, v, 0.0)
     corr = jnp.exp(m_prev - m_new)
     l_new = l_prev * corr + p.sum(axis=1, keepdims=True)
     pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
@@ -176,6 +181,7 @@ def paged_decode_window_attention(q, k_pages, v_pages, block_tables,
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVH, W * G, hd), q.dtype),
         interpret=interpret,
+        name="paged_decode_window_attention",
     )(block_tables, seq_lens, qr, kr, vr)
     return out.reshape(B, KVH, W, G, hd).transpose(0, 2, 1, 3, 4) \
               .reshape(B, W, H, hd)
@@ -220,5 +226,6 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, seq_lens, *,
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVH, G, hd), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(block_tables, seq_lens, qr, kr, vr)
     return out.reshape(B, 1, H, hd)

@@ -70,6 +70,7 @@ def pairwise_sqdist(theta, *, block_d: int = DEFAULT_BLOCK_D,
         out_specs=pl.BlockSpec((n, n), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, n), jnp.float32),
         interpret=interpret,
+        name="svgd_sqdist",
     )(theta)
 
 
@@ -106,5 +107,6 @@ def svgd_force(theta, grads, lengthscale, *, block_d: int = DEFAULT_BLOCK_D,
         out_specs=pl.BlockSpec((n, block_d), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((n, nb * block_d), jnp.float32),
         interpret=interpret,
+        name="svgd_force",
     )(ktn, ksum, inv_ell2, theta, grads)
     return phi[:, :D]

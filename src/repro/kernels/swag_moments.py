@@ -2,8 +2,10 @@
 
 mean' = (mean*n + theta)/(n+1); sq' = (sq*n + theta^2)/(n+1), fused in one
 pass over the flattened parameter vector (one HBM read of theta instead of
-two, one kernel launch instead of a tree of elementwise HLOs). Tiled
-(8, 1024) f32 blocks in VMEM.
+two, one kernel launch instead of a tree of elementwise HLOs). The vector
+is padded to a multiple of 8 * 1024 and viewed as (rows, 1024); the grid
+streams (8, 1024) f32 blocks (one full sublane x lane tile group) through
+VMEM.
 
 update_moments() is the pytree-level entry point used by repro.bdl.swag.
 """
@@ -15,7 +17,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-BLOCK = 8 * 1024
+LANES = 1024
+SUBLANES = 8
+BLOCK = SUBLANES * LANES
 
 
 def _resolve_interpret(interpret: Optional[bool]) -> bool:
@@ -45,22 +49,18 @@ def moments_flat(mean, sq_mean, params, n, *,
         mean = jnp.pad(mean, (0, pad))
         sq_mean = jnp.pad(sq_mean, (0, pad))
         params = jnp.pad(params, (0, pad))
-    shp = (nb, BLOCK)
+    shp = (nb * SUBLANES, LANES)
+    tile = pl.BlockSpec((SUBLANES, LANES), lambda i: (i, 0))
     n_arr = jnp.asarray(n, jnp.float32).reshape(1, 1)
     out_mean, out_sq = pl.pallas_call(
         _moments_kernel,
         grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
-            pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
-            pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
-        ],
-        out_specs=[pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
-                   pl.BlockSpec((1, BLOCK), lambda i: (i, 0))],
+        in_specs=[pl.BlockSpec((1, 1), lambda i: (0, 0)), tile, tile, tile],
+        out_specs=[tile, tile],
         out_shape=[jax.ShapeDtypeStruct(shp, jnp.float32),
                    jax.ShapeDtypeStruct(shp, jnp.float32)],
         interpret=interpret,
+        name="swag_moments",
     )(n_arr, mean.reshape(shp), sq_mean.reshape(shp), params.reshape(shp))
     return out_mean.reshape(-1)[:D], out_sq.reshape(-1)[:D]
 
@@ -82,15 +82,16 @@ def diag_std_flat(mean, sq_mean, *, interpret: Optional[bool] = None):
     if pad:
         mean = jnp.pad(mean, (0, pad))
         sq_mean = jnp.pad(sq_mean, (0, pad), constant_values=1.0)
-    shp = (nb, BLOCK)
+    shp = (nb * SUBLANES, LANES)
+    tile = pl.BlockSpec((SUBLANES, LANES), lambda i: (i, 0))
     out = pl.pallas_call(
         _diag_std_kernel,
         grid=(nb,),
-        in_specs=[pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
-                  pl.BlockSpec((1, BLOCK), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, BLOCK), lambda i: (i, 0)),
+        in_specs=[tile, tile],
+        out_specs=tile,
         out_shape=jax.ShapeDtypeStruct(shp, jnp.float32),
         interpret=interpret,
+        name="swag_diag_std",
     )(mean.reshape(shp), sq_mean.reshape(shp))
     return out.reshape(-1)[:D]
 

@@ -33,9 +33,7 @@ def is_initialized() -> bool:
     with _lock:
         if _initialized:
             return True
-    state = getattr(getattr(jax, "_src", None), "distributed", None)
-    client = getattr(getattr(state, "global_state", None), "client", None)
-    return client is not None
+    return jax.distributed.is_initialized()
 
 
 def initialize(coordinator_address: Optional[str] = None,

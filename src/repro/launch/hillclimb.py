@@ -22,7 +22,7 @@ from .mesh import make_production_mesh
 from .plans import plan_for
 from . import hlo_cost as hc
 from . import steps as steps_mod
-from .roofline import PEAK_FLOPS, HBM_BW, LINK_BW
+from .roofline import DRYRUN_DEVICE_KIND, peaks
 
 
 def measure(arch: str, shape_name: str, *, microbatches=None, particles=None,
@@ -46,11 +46,12 @@ def measure(arch: str, shape_name: str, *, microbatches=None, particles=None,
     cost = hc.cost(txt)
     coll = sum(cost["coll"].values())
     m = c.memory_analysis()
+    pk = peaks(DRYRUN_DEVICE_KIND)
     rec = {
         "arch": arch, "shape": shape_name, "plan": dataclasses.asdict(plan),
-        "t_compute_s": cost["flops"] / PEAK_FLOPS,
-        "t_memory_s": cost["bytes"] / HBM_BW,
-        "t_collective_s": coll / LINK_BW,
+        "t_compute_s": cost["flops"] / pk["flops"],
+        "t_memory_s": cost["bytes"] / pk["hbm_bw"],
+        "t_collective_s": coll / pk["link_bw"],
         "coll_tb": {k: round(v / 1e12, 3) for k, v in cost["coll"].items()},
         "hbm_temp_gb": m.temp_size_in_bytes / 1e9,
         "hbm_args_gb": m.argument_size_in_bytes / 1e9,

@@ -1,10 +1,8 @@
 """Production mesh factory (functions only — importing never touches jax
 device state; the dry-run sets XLA_FLAGS before any jax import).
 
-``AxisType`` (explicit-sharding axis annotations) only exists in newer jax
-releases; ``make_mesh`` shims it so the same call sites work on any
-installed version — older jax simply builds the mesh without axis types
-(every axis behaves as Auto there anyway).
+Every mesh is built with ``AxisType.Auto`` axes: GSPMD propagates the
+shardings the placement layer annotates.
 
 All factories validate axis sizes against the visible device count up
 front and raise a clear ``ValueError`` — a bad ``model=`` used to surface
@@ -16,13 +14,7 @@ import math
 from typing import Optional
 
 import jax
-
-try:  # newer jax: explicit-sharding axis types
-    from jax.sharding import AxisType
-except ImportError:  # older jax: all axes are implicitly Auto
-    AxisType = None
-
-HAS_AXIS_TYPES = AxisType is not None
+from jax.sharding import AxisType
 
 
 def _validate(shape, axes) -> None:
@@ -50,12 +42,9 @@ def _validate(shape, axes) -> None:
 
 
 def make_mesh(shape, axes):
-    """Version-portable ``jax.make_mesh`` with Auto axis types when available."""
+    """Validated ``jax.make_mesh`` with Auto axis types."""
     _validate(shape, axes)
-    if HAS_AXIS_TYPES:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -3,14 +3,16 @@
 Per (arch x shape) on the single-pod mesh, derive the three roofline terms
 from the compiled per-device program:
 
-  compute term    = HLO_FLOPs_per_device / PEAK_FLOPS          [s]
-  memory term     = HLO_bytes_per_device / HBM_BW              [s]
-  collective term = collective_bytes_per_device / LINK_BW      [s]
+  compute term    = HLO_FLOPs_per_device / peak FLOP/s            [s]
+  memory term     = HLO_bytes_per_device / peak HBM bytes/s       [s]
+  collective term = collective_bytes_per_device / link bytes/s    [s]
 
-Hardware: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
-cost_analysis() is taken from the SPMD-partitioned (per-device) module, so
-all three terms are per-device quantities; MODEL_FLOPS is scaled to
-per-device for the usefulness ratio.
+The peaks come from ``PEAKS``, one table keyed by jax's ``device_kind``;
+a device that is not in it is an error, never a default. The dry run
+targets the v5e production mesh, so ``DRYRUN_DEVICE_KIND`` names that
+entry. cost_analysis() is taken from the SPMD-partitioned (per-device)
+module, so all three terms are per-device quantities; MODEL_FLOPS is
+scaled to per-device for the usefulness ratio.
 
 Usage:  PYTHONPATH=src python -m repro.launch.roofline --runs runs/dryrun
         (writes a markdown table to stdout + runs/roofline.json)
@@ -23,10 +25,25 @@ import json
 import os
 import re
 
-PEAK_FLOPS = 197e12      # bf16 / chip
-HBM_BW = 819e9           # bytes/s
-LINK_BW = 50e9           # bytes/s/link
+# Per-chip peaks keyed by ``jax.devices()[0].device_kind``.
+# TPU v5e — Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+# 16 GB HBM at 819 GB/s, 1,600 Gbit/s inter-chip interconnect over 4 links
+# (50 GB/s per link).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9},
+}
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
 CHIPS = 256              # single-pod mesh
+
+
+def peaks(device_kind: str) -> dict:
+    """Peak rates of one chip of ``device_kind``; unknown kinds raise."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak rates recorded for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}") from None
 
 _COUNT_CACHE = {}
 
@@ -73,6 +90,7 @@ def model_flops(rec, shapes):
 
 def analyze(runs_dir: str, mesh: str = "single"):
     from ..configs import INPUT_SHAPES
+    pk = peaks(DRYRUN_DEVICE_KIND)
     rows = []
     for f in sorted(glob.glob(os.path.join(runs_dir, f"*__{mesh}.json"))):
         r = json.load(open(f))
@@ -80,9 +98,9 @@ def analyze(runs_dir: str, mesh: str = "single"):
             rows.append({**r, "dominant": "-"})
             continue
         coll = sum(r["collective_bytes_per_device"].values())
-        t_c = r["flops_per_device"] / PEAK_FLOPS
-        t_m = r["bytes_per_device"] / HBM_BW
-        t_n = coll / LINK_BW
+        t_c = r["flops_per_device"] / pk["flops"]
+        t_m = r["bytes_per_device"] / pk["hbm_bw"]
+        t_n = coll / pk["link_bw"]
         dom = max(("compute", t_c), ("memory", t_m), ("collective", t_n),
                   key=lambda kv: kv[1])[0]
         mf = model_flops(r, INPUT_SHAPES)

@@ -127,7 +127,14 @@ class NelRuntime(_BaseRuntime):
         futs = [pd.particles[pid].forward(batch)
                 for pid in pd.particle_ids()]
         outs = [f.wait() for f in futs]
-        return jax.tree.map(lambda *xs: sum(xs) / len(xs), *outs)
+
+        def mean(*xs):
+            # particles on several devices: average on the first one's
+            home = xs[0].sharding
+            return sum(x if x.sharding == home else jax.device_put(x, home)
+                       for x in xs) / len(xs)
+
+        return jax.tree.map(mean, *outs)
 
 
 class CompiledRuntime(_BaseRuntime):

@@ -151,6 +151,11 @@ class ProgramCache:
             s["hit_rate"] = s["hits"] / total if total else 0.0
             return s
 
+    def programs(self) -> List[Program]:
+        """The resident programs, least recently used first."""
+        with self._lock:
+            return list(self._programs.values())
+
     def program_costs(self, compute: bool = False) -> List[Dict[str, Any]]:
         """Per-entry cost attribution (obs.device): name, key
         fingerprint, particle count, eager per-device param bytes, and —

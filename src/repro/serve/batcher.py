@@ -371,6 +371,11 @@ class DecodeScheduler:
     PR 1 Executor, like MicroBatcher — idle scheduler, parked worker.
     """
 
+    # a prefill of this many tokens or fewer is bound by reading the
+    # weights, not by its token count, so padding a prompt up to it is
+    # cheaper than compiling a program for a smaller bucket
+    PREFILL_FREE_PAD = 16
+
     def __init__(self, engine, pool, *, max_active: int = 8,
                  eos_id: Optional[int] = None, max_queue: int = 256,
                  executor: Optional[Executor] = None):
@@ -565,9 +570,22 @@ class DecodeScheduler:
             self._prefill_bufs[bucket] = buf
         return buf
 
+    def _prefill_bucket(self, n_pf: int) -> int:
+        """The prompt's own pow2 bucket, or the smallest bucket that
+        already has a program (warmed or used before) and pads the prompt
+        by at most 2x its own bucket or up to ``PREFILL_FREE_PAD`` tokens.
+        Prefill masks by ``n_tokens``, so a wider bucket changes only the
+        padding: a very short prompt reuses a warmed program instead of
+        cold-compiling a tiny one, while a service warmed only at large
+        buckets still runs short prompts at their own size."""
+        own = bucket_size(n_pf)
+        limit = max(2 * own, self.PREFILL_FREE_PAD)
+        fits = [b for b in self._prefill_bufs if own <= b <= limit]
+        return min(fits) if fits else own
+
     def _prefill(self, seq: _Seq, n_pf: int):
         tokens = seq.all_tokens[:n_pf]
-        bucket = bucket_size(n_pf)
+        bucket = self._prefill_bucket(n_pf)
         with _trace.span("decode.prefill", "decode", sid=seq.sid,
                          tokens=n_pf, bucket=bucket):
             buf = self._prefill_buf(bucket)

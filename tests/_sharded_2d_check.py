@@ -22,6 +22,9 @@ Asserts, over a ``particle=2 x model=2`` mesh (the tentpole of ISSUE 7):
 import os
 import sys
 
+# forced host devices: pin the CPU backend so a chip on the host is
+# never claimed by this check
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=4")
 
 import jax

@@ -27,6 +27,9 @@ import json
 import os
 import sys
 
+# forced host devices: pin the CPU backend so a chip on the host is
+# never claimed by this check
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=4")
 
 import jax

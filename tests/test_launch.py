@@ -13,12 +13,6 @@ from repro.launch import steps as S
 from repro.launch import mesh as mesh_compat
 from repro.launch.plans import plan_for
 
-# Building sharded steps needs the explicit-sharding API (AxisType +
-# jax.set_mesh); plan/cost tests below run on any jax version.
-needs_explicit_sharding = pytest.mark.skipif(
-    not (mesh_compat.HAS_AXIS_TYPES and hasattr(jax, "set_mesh")),
-    reason="installed jax lacks the explicit-sharding API (AxisType/set_mesh)")
-
 
 def tiny_mesh():
     return mesh_compat.make_mesh((1, 1), ("data", "model"))
@@ -83,7 +77,6 @@ def test_plans_are_coherent(arch, shape):
         assert plan.particles % 16 == 0  # must shard over data=16
 
 
-@needs_explicit_sharding
 def test_build_shapes_ensemble_train():
     cfg = configs.get("qwen1.5-0.5b").smoke()
     shp = INPUT_SHAPES["train_4k"]
@@ -100,7 +93,6 @@ def test_build_shapes_ensemble_train():
     assert out[2].shape == (2, )  # per-particle losses
 
 
-@needs_explicit_sharding
 def test_build_shapes_svgd_train():
     cfg = configs.get("qwen1.5-0.5b").smoke()
     import dataclasses
@@ -116,7 +108,6 @@ def test_build_shapes_svgd_train():
     assert jax.tree.structure(out[0]) == jax.tree.structure(args[0])
 
 
-@needs_explicit_sharding
 def test_build_decode_cache_roundtrip():
     cfg = configs.get("gemma3-4b").smoke()
     import dataclasses
@@ -150,3 +141,28 @@ def test_hlo_cost_trip_counts():
     c = hc.cost(txt)
     expected = 2 * 8 * 8 * 8 * 7  # 7 iterations of an 8x8x8 matmul
     assert c["flops"] == pytest.approx(expected, rel=0.01), c["flops"]
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    from repro.launch import roofline
+    v5e = roofline.peaks("TPU v5 lite")
+    assert v5e["flops"] == 197e12 and v5e["hbm_bw"] == 819e9
+    assert roofline.DRYRUN_DEVICE_KIND in roofline.PEAKS
+    with pytest.raises(ValueError, match="no peak rates"):
+        roofline.peaks("cpu")
+
+
+def test_compile_cache_dir_from_env_else_checkout(monkeypatch, tmp_path):
+    from repro.launch.compile_cache import use_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        env_dir = str(tmp_path / "from_env")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        assert use_compile_cache(str(tmp_path)) == env_dir
+        assert jax.config.jax_compilation_cache_dir == before  # left to JAX
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        want = str(tmp_path / ".jax_cache")
+        assert use_compile_cache(str(tmp_path)) == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -326,3 +326,20 @@ def test_scheduler_eos_and_request_validation():
                 svc.generate([1] * 100, max_new=100)
         finally:
             svc.close()
+
+
+@pytest.mark.parametrize("warmed,n_pf,want", [
+    ((4, 8, 16), 1, 4),       # a tiny prompt reuses the smallest warmed
+    ((4, 8, 16), 5, 8),
+    ((512,), 5, 8),           # a large warmed bucket is not reused for it
+    ((512,), 300, 512),
+    ((1024,), 300, 1024),     # up to 2x the prompt's own bucket (512)
+    ((2048,), 300, 512),      # beyond that: the prompt's own bucket
+    ((), 3, 4),
+])
+def test_prefill_bucket_reuse_is_bounded(warmed, n_pf, want):
+    from types import SimpleNamespace
+    from repro.serve.batcher import DecodeScheduler
+    sched = SimpleNamespace(_prefill_bufs=dict.fromkeys(warmed),
+                            PREFILL_FREE_PAD=DecodeScheduler.PREFILL_FREE_PAD)
+    assert DecodeScheduler._prefill_bucket(sched, n_pf) == want

@@ -536,6 +536,7 @@ def test_sharded_serving_reads_store_without_unsharding():
     out = subprocess.run(
         [sys.executable, os.path.join(REPO, "tests", "_sharded_serve_check.py")],
         env={**os.environ, "PYTHONPATH": os.path.join(REPO, "src"),
+             "JAX_PLATFORMS": "cpu",
              "XLA_FLAGS": "--xla_force_host_platform_device_count=4"},
         capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, (out.stdout[-2000:], out.stderr[-2000:])

@@ -99,19 +99,17 @@ def test_training_reduces_loss_lm():
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(not hasattr(jax, "set_mesh"),
-                    reason="dryrun needs the explicit-sharding API "
-                           "(jax.set_mesh) in the subprocess")
-def test_dryrun_subprocess_production_mesh():
+def test_dryrun_subprocess_production_mesh(tmp_path):
     """Deliverable (e) check: lower+compile on the 16x16 production mesh in a
     fresh process (512 forced host devices)."""
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--arch", "qwen1.5-0.5b",
          "--shape", "decode_32k", "--mesh", "single", "--out",
-         "/tmp/test_dryrun"],
-        env={**os.environ, "PYTHONPATH": os.path.join(REPO, "src")},
+         str(tmp_path)],
+        env={**os.environ, "PYTHONPATH": os.path.join(REPO, "src"),
+             "JAX_PLATFORMS": "cpu"},
         capture_output=True, text=True, timeout=900)
     assert out.returncode == 0, out.stderr[-2000:]
-    rec = json.load(open("/tmp/test_dryrun/qwen1.5-0.5b__decode_32k__single.json"))
+    rec = json.load(open(tmp_path / "qwen1.5-0.5b__decode_32k__single.json"))
     assert rec["status"] == "ok", rec
     assert rec["flops_per_device"] > 0
