@@ -49,7 +49,7 @@ a minimum hit rate via ``bench_compile --require-hit-rate``.
                                           params+opt bytes >= 1.8x)
 
 Obs rows land in ``BENCH_obs.json``; every BENCH_*.json additionally
-carries an ``obs`` context block (tracer/registry state + per-program
+carries an ``obs`` context block (tracer state + per-program
 FLOPs/bytes cost attribution from the global ProgramCache).
 """
 import argparse

@@ -54,7 +54,7 @@ def device_info() -> dict:
 
 def obs_context() -> dict:
     """Observability context attached to every BENCH_*.json artifact:
-    tracer/registry state plus per-program cost attribution for whatever
+    tracer state plus per-program cost attribution for whatever
     the global ProgramCache compiled during the run (compute=True pays
     one analysis compile per entry — fine post-benchmark, off any timed
     path)."""

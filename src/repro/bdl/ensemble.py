@@ -16,6 +16,7 @@ devices (``spmd_axis_name`` + explicit in/out shardings).
 """
 from __future__ import annotations
 
+from ..obs.trace import traced
 from ..runtime import specs
 from .infer import Infer
 
@@ -38,6 +39,7 @@ class DeepEnsemble(Infer):
                                     optimizer=optimizer)
         return pids, losses
 
+    @traced("bdl.fused_call", "bdl")
     def _fused_epochs(self, pids, dataloader, epochs: int, *, optimizer):
         """Train existing particles for `epochs` through the fused program
         (store checkout -> donated compiled loop -> one commit). Reused by
@@ -56,7 +58,7 @@ class DeepEnsemble(Infer):
                                           co["opt_state"], batch, mask)
                     co["params"], co["opt_state"], ls = prog(
                         co["params"], co["opt_state"], batch, mask)
-        return [] if ls is None else [float(ls[s]) for s in slots]
+        return self._read_losses(ls, slots)
 
 
 def compiled_ensemble_step(module, optimizer):

@@ -27,6 +27,7 @@ from typing import Callable, Optional, Union
 import jax
 
 from ..core import ParticleModule, Placement, PushDistribution
+from ..obs import trace as _trace
 
 
 def _init_shapes(module):
@@ -133,12 +134,24 @@ class Infer:
             else CompiledRuntime(self.push_dist, rt.cache)
 
     @staticmethod
+    def _read_losses(ls, slots):
+        """The per-particle losses of one fused run on the host (``[]``
+        when no batch ran): a ``bdl.device_wait`` span while the device
+        finishes the steps the host has run ahead of, then one
+        ``float(ls[s])`` per slot in a ``bdl.loss_sync`` span."""
+        if ls is None:
+            return []
+        with _trace.span("bdl.device_wait", "bdl"):
+            ls.block_until_ready()
+        with _trace.span("bdl.loss_sync", "bdl", particles=len(slots)):
+            return [float(ls[s]) for s in slots]
+
+    @staticmethod
     def _traced_epochs(epochs: int, label: str):
         """Iterate ``range(epochs)``, bracketing each epoch's body (the
         code between yields) in an obs ``bdl.epoch`` span plus a
         ``jax.profiler.StepTraceAnnotation`` so device profiles show
         per-epoch step markers. Free when tracing is off."""
-        from ..obs import trace as _trace
         if not _trace.enabled():
             yield from range(epochs)
             return

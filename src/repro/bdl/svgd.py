@@ -34,6 +34,7 @@ from jax.flatten_util import ravel_pytree
 
 from ..core import functional
 from ..core.store import Placement
+from ..obs.trace import traced
 from .infer import Infer
 
 
@@ -304,6 +305,7 @@ class SteinVGD(Infer):
                                     lengthscale=lengthscale)
         return pids, losses
 
+    @traced("bdl.fused_call", "bdl")
     def _fused_epochs(self, pids, dataloader, epochs: int, *,
                       lr: float = 1e-3, lengthscale: float = 1.0):
         rt = self._compiled_runtime()
@@ -318,4 +320,4 @@ class SteinVGD(Infer):
                     if prog is None:  # one cache lookup per fused run
                         prog = rt.program(spec, co["params"], batch, mask)
                     co["params"], ls = prog(co["params"], batch, mask)
-        return [] if ls is None else [float(ls[s]) for s in slots]
+        return self._read_losses(ls, slots)

@@ -24,6 +24,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ..obs.trace import traced
 from .infer import Infer
 
 
@@ -189,6 +190,7 @@ class MultiSWAG(Infer):
                                     pretrain_epochs=pretrain_epochs)
         return pids, losses
 
+    @traced("bdl.fused_call", "bdl")
     def _fused_epochs(self, pids, dataloader, epochs: int, *, optimizer,
                       pretrain_epochs: int = 0):
         """Stacked-axis multi-SWAG on existing particles — two thin
@@ -222,7 +224,7 @@ class MultiSWAG(Infer):
                         collect = rt.program(collect_spec, co["swag"],
                                              co["params"], mask)
                     co["swag"] = collect(co["swag"], co["params"], mask)
-        return [] if ls is None else [float(ls[s]) for s in slots]
+        return self._read_losses(ls, slots)
 
     def posterior_predictive(self, *, samples_per_particle: int = 0,
                              rng=None, scale: float = 1.0,

@@ -116,22 +116,27 @@ def ensemble_step(loss_fn: Callable, optimizer,
     vag = ensemble_value_and_grad(loss_fn, spmd_axis_name)
 
     def step(stacked_params, stacked_opt_state, batch, mask=None):
-        if compute_dtype is not None:
-            from .precision import cast_floats
-            losses, grads = vag(cast_floats(stacked_params, compute_dtype),
-                                cast_floats(batch, compute_dtype))
-            grads = jax.tree.map(lambda g, p: g.astype(p.dtype),
-                                 grads, stacked_params)
-            losses = losses.astype(jnp.float32)
-        else:
-            losses, grads = vag(stacked_params, batch)
-        new_p, new_s = jax.vmap(optimizer.update,
-                                spmd_axis_name=spmd_axis_name)(
-            stacked_params, grads, stacked_opt_state)
-        if mask is not None:
-            new_p = masked_select(mask, new_p, stacked_params)
-            new_s = masked_select(mask, new_s, stacked_opt_state)
-            losses = jnp.where(mask > 0, losses, 0.0)
+        # device-side names: a profile splits the step's time into the
+        # forward/backward pass and the optimizer update
+        with jax.named_scope("push.loss_grad"):
+            if compute_dtype is not None:
+                from .precision import cast_floats
+                losses, grads = vag(
+                    cast_floats(stacked_params, compute_dtype),
+                    cast_floats(batch, compute_dtype))
+                grads = jax.tree.map(lambda g, p: g.astype(p.dtype),
+                                     grads, stacked_params)
+                losses = losses.astype(jnp.float32)
+            else:
+                losses, grads = vag(stacked_params, batch)
+        with jax.named_scope("push.optimizer"):
+            new_p, new_s = jax.vmap(optimizer.update,
+                                    spmd_axis_name=spmd_axis_name)(
+                stacked_params, grads, stacked_opt_state)
+            if mask is not None:
+                new_p = masked_select(mask, new_p, stacked_params)
+                new_s = masked_select(mask, new_s, stacked_opt_state)
+                losses = jnp.where(mask > 0, losses, 0.0)
         return new_p, new_s, losses
 
     return step

@@ -10,6 +10,7 @@ from typing import Iterator, Optional
 
 import numpy as np
 
+from ..obs import trace as _trace
 from .synthetic import make_batch
 
 
@@ -30,4 +31,7 @@ class DataLoader:
         rng = np.random.default_rng((self.seed, self._epoch))
         self._epoch += 1
         for _ in range(self.num_batches):
-            yield make_batch(self.cfg, rng, self.batch_size, self.seq_len)
+            with _trace.span("data.next", "data"):
+                batch = make_batch(self.cfg, rng, self.batch_size,
+                                   self.seq_len)
+            yield batch

@@ -260,14 +260,16 @@ def decode_step_paged(params, tokens, pages, block_tables, seq_lens, cfg, *,
     Returns (logits (B, V), pages)."""
     paged_guard(cfg)
     dtype = jnp.dtype(cfg.dtype)
-    x = _embed(params, jnp.maximum(tokens, 0)[:, None], cfg, dtype)
+    with jax.named_scope("push.embed"):
+        x = _embed(params, jnp.maximum(tokens, 0)[:, None], cfg, dtype)
     ctx: Dict[str, Any] = {"cache_dtype": _cache_dtype(cfg),
                            "block_tables": block_tables,
                            "seq_lens": seq_lens,
                            "decode_kernel": decode_kernel}
     x, pages = stack_apply_paged(params, x, cfg, pages, ctx)
-    x = norm_apply(params["final_norm"], x)
-    logits = _lm_logits(params, x, cfg)
+    with jax.named_scope("push.lm_head"):
+        x = norm_apply(params["final_norm"], x)
+        logits = _lm_logits(params, x, cfg)
     return logits[:, 0], pages
 
 
@@ -304,12 +306,15 @@ def prefill_paged(params, tokens, pages, block_table_row, n_tokens, cfg):
     Returns (last-real-token logits (1, V), pages)."""
     paged_guard(cfg)
     dtype = jnp.dtype(cfg.dtype)
-    x = _embed(params, tokens, cfg, dtype)
+    with jax.named_scope("push.embed"):
+        x = _embed(params, tokens, cfg, dtype)
     ctx: Dict[str, Any] = {"cache_dtype": _cache_dtype(cfg),
                            "block_table_row": block_table_row,
                            "n_tokens": n_tokens}
     x, pages = stack_apply_prefill_paged(params, x, cfg, pages, ctx)
-    x = norm_apply(params["final_norm"], x)
-    last = lax.dynamic_slice_in_dim(x, jnp.maximum(n_tokens - 1, 0), 1, axis=1)
-    logits = _lm_logits(params, last, cfg)
+    with jax.named_scope("push.lm_head"):
+        x = norm_apply(params["final_norm"], x)
+        last = lax.dynamic_slice_in_dim(x, jnp.maximum(n_tokens - 1, 0), 1,
+                                        axis=1)
+        logits = _lm_logits(params, last, cfg)
     return logits[:, 0], pages

@@ -323,42 +323,46 @@ def paged_guard(cfg):
         raise NotImplementedError("paged decode does not support prefix_lm")
 
 
+# device-side names (jax.named_scope, in the HLO's op metadata): a
+# profile splits each paged program's device time into push.attention
+# (with its paged KV write) and push.mlp per layer
+
+
+def _ffn_paged(kind, p, x, cfg):
+    with jax.named_scope("push.mlp"):
+        if kind == "attn_moe":
+            h, _ = moe_mod.moe_apply(p["moe"], norm_apply(p["ln2"], x), cfg)
+        else:
+            h = mlp_apply(p["mlp"], norm_apply(p["ln2"], x), cfg)
+    return x + h
+
+
 def _layer_apply_paged(kind, p, x, cfg, pages, ctx):
-    h, pages = attn_apply_paged(
-        p["attn"], norm_apply(p["ln1"], x), cfg, pages,
-        block_tables=ctx["block_tables"], seq_lens=ctx["seq_lens"],
-        use_kernel=ctx.get("decode_kernel", True))
-    x = x + h
-    if kind == "attn_moe":
-        h, _ = moe_mod.moe_apply(p["moe"], norm_apply(p["ln2"], x), cfg)
-    else:
-        h = mlp_apply(p["mlp"], norm_apply(p["ln2"], x), cfg)
-    return x + h, pages
+    with jax.named_scope("push.attention"):
+        h, pages = attn_apply_paged(
+            p["attn"], norm_apply(p["ln1"], x), cfg, pages,
+            block_tables=ctx["block_tables"], seq_lens=ctx["seq_lens"],
+            use_kernel=ctx.get("decode_kernel", True))
+    return _ffn_paged(kind, p, x + h, cfg), pages
 
 
 def _layer_apply_window_paged(kind, p, x, cfg, pages, ctx):
-    h, pages = attn_apply_window_paged(
-        p["attn"], norm_apply(p["ln1"], x), cfg, pages,
-        block_tables=ctx["block_tables"], seq_lens=ctx["seq_lens"],
-        win_lens=ctx["win_lens"], use_kernel=ctx.get("decode_kernel", True))
-    x = x + h
-    if kind == "attn_moe":
-        h, _ = moe_mod.moe_apply(p["moe"], norm_apply(p["ln2"], x), cfg)
-    else:
-        h = mlp_apply(p["mlp"], norm_apply(p["ln2"], x), cfg)
-    return x + h, pages
+    with jax.named_scope("push.attention"):
+        h, pages = attn_apply_window_paged(
+            p["attn"], norm_apply(p["ln1"], x), cfg, pages,
+            block_tables=ctx["block_tables"], seq_lens=ctx["seq_lens"],
+            win_lens=ctx["win_lens"],
+            use_kernel=ctx.get("decode_kernel", True))
+    return _ffn_paged(kind, p, x + h, cfg), pages
 
 
 def _layer_apply_prefill_paged(kind, p, x, cfg, pages, ctx):
-    h, pages = attn_apply_prefill_paged(
-        p["attn"], norm_apply(p["ln1"], x), cfg, pages,
-        block_table_row=ctx["block_table_row"], n_tokens=ctx["n_tokens"])
-    x = x + h
-    if kind == "attn_moe":
-        h, _ = moe_mod.moe_apply(p["moe"], norm_apply(p["ln2"], x), cfg)
-    else:
-        h = mlp_apply(p["mlp"], norm_apply(p["ln2"], x), cfg)
-    return x + h, pages
+    with jax.named_scope("push.attention"):
+        h, pages = attn_apply_prefill_paged(
+            p["attn"], norm_apply(p["ln1"], x), cfg, pages,
+            block_table_row=ctx["block_table_row"],
+            n_tokens=ctx["n_tokens"])
+    return _ffn_paged(kind, p, x + h, cfg), pages
 
 
 def _stack_apply_paged_common(params, x, cfg, pages, ctx, layer_fn):

@@ -3,8 +3,8 @@
 # trace.py   — thread-safe bounded-ring span recorder, compiled-out when
 #              disabled; instruments executor / store / cache / serve /
 #              decode / bdl (span taxonomy: DESIGN.md §12)
-# metrics.py — Counter/Gauge/Histogram registry + the one percentile
-#              implementation behind every latency_p* stats key
+# metrics.py — latency Histogram + the one percentile implementation
+#              behind every latency_p* stats key
 # device.py  — per-device memory gauges, store/page-pool occupancy,
 #              per-Program FLOPs/bytes cost attribution (hlo_cost +
 #              compiled.cost_analysis)
@@ -18,7 +18,7 @@ from . import clock, export, metrics, trace
 
 
 def summary() -> Dict[str, Any]:
-    """The ``pd.stats()["obs"]`` section: tracer + registry state."""
+    """The ``pd.stats()["obs"]`` section: the tracer's state."""
     c = trace.TRACER.counts()
     return {
         "tracing_enabled": trace.TRACER.enabled,
@@ -27,7 +27,6 @@ def summary() -> Dict[str, Any]:
         "spans_dropped": c["dropped"],
         "ring": trace.TRACER.ring,
         "clock": "perf_counter",
-        "metrics": metrics.REGISTRY.size(),
     }
 
 
@@ -63,4 +62,4 @@ class Obs:
         return export.dump_chrome_trace(path)
 
     def prometheus(self) -> str:
-        return export.prometheus_text(extra=self.pd.stats())
+        return export.prometheus_text(self.pd.stats())

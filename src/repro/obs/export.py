@@ -7,10 +7,9 @@ complete events (``ph: "X"``, ``ts``/``dur`` in microseconds since
 zero-duration marks, and ``thread_name`` metadata events so executor
 workers show up as labelled tracks.
 
-``prometheus_text()`` renders the metric registry — typed metrics as
-counter/gauge/summary lines, pull collectors (the ``pd.stats()``
-sections) flattened to gauges — in the text exposition format a
-Prometheus scrape endpoint would serve.
+``prometheus_text()`` renders a ``pd.stats()`` snapshot — every numeric
+leaf flattened to a gauge — in the text exposition format a Prometheus
+scrape endpoint would serve.
 """
 from __future__ import annotations
 
@@ -19,7 +18,7 @@ import os
 import re
 from typing import Any, Dict, List, Optional
 
-from . import clock, metrics, trace
+from . import clock, trace
 
 # ---------------------------------------------------------------------------
 # Chrome / Perfetto trace-event JSON
@@ -81,13 +80,6 @@ def _san(name: str) -> str:
     return name
 
 
-def _labels(lab) -> str:
-    if not lab:
-        return ""
-    inner = ",".join(f'{_san(k)}="{v}"' for k, v in lab)
-    return "{" + inner + "}"
-
-
 def _flatten(prefix: str, obj, out: Dict[str, float]):
     """Numeric leaves of a nested stats dict -> flat metric names."""
     if isinstance(obj, bool):
@@ -103,35 +95,12 @@ def _flatten(prefix: str, obj, out: Dict[str, float]):
     # strings / None / objects are dropped: exposition is numeric
 
 
-def prometheus_text(registry: Optional[metrics.Registry] = None,
-                    extra: Optional[Dict[str, Any]] = None,
-                    prefix: str = "repro") -> str:
-    """Registry metrics + pull collectors (+ an optional extra nested
-    dict, e.g. a ``pd.stats()`` snapshot) in text exposition format."""
-    registry = registry if registry is not None else metrics.REGISTRY
-    lines: List[str] = []
-    for m in registry.collect():
-        name = _san(f"{prefix}_{m.name}")
-        lab = _labels(m.labels)
-        if m.kind == "histogram":
-            lines.append(f"# TYPE {name} summary")
-            snap = m.snapshot()
-            for q, key in ((0.5, "p50"), (0.95, "p95"), (0.99, "p99")):
-                ql = dict(m.labels) if m.labels else {}
-                ql["quantile"] = q
-                lines.append(f"{name}{_labels(tuple(ql.items()))} "
-                             f"{snap[key]}")
-            lines.append(f"{name}_count{lab} {snap['count']}")
-            lines.append(f"{name}_sum{lab} {snap['sum']}")
-        else:
-            lines.append(f"# TYPE {name} {m.kind}")
-            lines.append(f"{name}{lab} {float(m.value)}")
+def prometheus_text(stats: Dict[str, Any], prefix: str = "repro") -> str:
+    """The numeric leaves of a nested stats dict (a ``pd.stats()``
+    snapshot) as gauges, in text exposition format."""
     flat: Dict[str, float] = {}
-    for cprefix, values in registry.collector_values().items():
-        _flatten(f"{prefix}_{_san(cprefix)}", values, flat)
-    if extra:
-        for k, v in extra.items():
-            _flatten(f"{prefix}_{_san(str(k))}", v, flat)
+    _flatten(prefix, stats, flat)
+    lines: List[str] = []
     for name in sorted(flat):
         lines.append(f"# TYPE {name} gauge")
         lines.append(f"{name} {flat[name]}")

@@ -1,17 +1,12 @@
-"""obs.metrics — typed metric registry (Counter / Gauge / Histogram).
+"""obs.metrics — latency distributions: ``Histogram`` and the one
+``percentile`` implementation.
 
-Two usage modes, matching how the repo's stats actually grew:
-
-  * **Native metrics**: code that needs a latency distribution or a
-    monotonically increasing count creates a typed metric. The serving
-    layer's three duplicated latency implementations
-    (``service.percentile`` + two hand-rolled ``_latencies`` ring
-    deques in batcher.py) collapse onto ``Histogram`` — same ring
-    bound, same ``np.percentile`` semantics, one implementation.
-  * **Pull collectors**: the existing ``pd.stats()`` sections stay the
-    canonical counters (their keys are asserted byte-compatible by
-    tests/test_obs.py); the registry exports them to Prometheus via
-    registered collector callables instead of duplicating them.
+The serving layer's three duplicated latency implementations
+(``service.percentile`` + two hand-rolled ``_latencies`` ring deques in
+batcher.py) collapse onto ``Histogram`` — same ring bound, same
+``np.percentile`` semantics, one implementation. Counters live in the
+``pd.stats()`` sections themselves (their keys are asserted by
+tests/test_obs.py), and the Prometheus exporter renders that snapshot.
 
 Histograms keep a bounded ring of raw observations (default 4096 — the
 serving layer's historical ``_LAT_RING``) so percentiles are exact over
@@ -19,9 +14,8 @@ the recent window, plus lifetime count/sum for rate math.
 """
 from __future__ import annotations
 
-import threading
 from collections import deque
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Dict, List
 
 import numpy as np
 
@@ -38,62 +32,16 @@ def percentile(xs, q: float) -> float:
     return float(np.percentile(xs, q))
 
 
-class _Metric:
-    __slots__ = ("name", "labels")
-
-    def __init__(self, name: str, labels: Tuple[Tuple[str, str], ...] = ()):
-        self.name = name
-        self.labels = labels
-
-
-class Counter(_Metric):
-    kind = "counter"
-    __slots__ = ("_v",)
-
-    def __init__(self, name: str, labels=()):
-        super().__init__(name, labels)
-        self._v = 0
-
-    def inc(self, n: int = 1):
-        self._v += n
-
-    @property
-    def value(self):
-        return self._v
-
-
-class Gauge(_Metric):
-    kind = "gauge"
-    __slots__ = ("_v", "_fn")
-
-    def __init__(self, name: str, labels=()):
-        super().__init__(name, labels)
-        self._v = 0.0
-        self._fn: Optional[Callable[[], float]] = None
-
-    def set(self, v: float):
-        self._v = v
-
-    def set_fn(self, fn: Callable[[], float]):
-        """Pull gauge: ``value`` calls ``fn`` at read time."""
-        self._fn = fn
-
-    @property
-    def value(self):
-        return self._fn() if self._fn is not None else self._v
-
-
-class Histogram(_Metric):
+class Histogram:
     """Bounded-ring distribution: exact percentiles over the last
     ``ring`` observations, lifetime count/sum. ``observe`` is lock-free
     for the same reason the tracer's record is (bounded-deque append is
     atomic; count/sum are best-effort under concurrent writers, exact
     under the single pump threads that own them here)."""
-    kind = "histogram"
-    __slots__ = ("_ring", "count", "sum")
+    __slots__ = ("name", "_ring", "count", "sum")
 
-    def __init__(self, name: str, labels=(), ring: int = DEFAULT_RING):
-        super().__init__(name, labels)
+    def __init__(self, name: str, ring: int = DEFAULT_RING):
+        self.name = name
         self._ring: deque = deque(maxlen=ring)
         self.count = 0
         self.sum = 0.0
@@ -114,70 +62,3 @@ class Histogram(_Metric):
         return {"count": self.count, "sum": self.sum,
                 "p50": percentile(xs, 50), "p95": percentile(xs, 95),
                 "p99": percentile(xs, 99)}
-
-
-class Registry:
-    """Name+labels -> metric, get-or-create; plus pull collectors that
-    surface existing stats dicts at export time without copying them
-    into typed metrics."""
-
-    def __init__(self):
-        self._lock = threading.Lock()
-        self._metrics: Dict[Tuple, _Metric] = {}
-        self._collectors: List[Tuple[str, Callable[[], Dict]]] = []
-
-    def _get(self, cls, name: str, labels: Dict[str, Any], **kw):
-        lab = tuple(sorted((k, str(v)) for k, v in labels.items()))
-        key = (name, lab)
-        with self._lock:
-            m = self._metrics.get(key)
-            if m is None:
-                m = cls(name, lab, **kw)
-                self._metrics[key] = m
-            elif not isinstance(m, cls):
-                raise TypeError(
-                    f"metric {name!r}{dict(lab)} exists as {m.kind}")
-            return m
-
-    def counter(self, name: str, **labels) -> Counter:
-        return self._get(Counter, name, labels)
-
-    def gauge(self, name: str, **labels) -> Gauge:
-        return self._get(Gauge, name, labels)
-
-    def histogram(self, name: str, ring: int = DEFAULT_RING,
-                  **labels) -> Histogram:
-        return self._get(Histogram, name, labels, ring=ring)
-
-    def register_collector(self, prefix: str, fn: Callable[[], Dict]):
-        """``fn()`` returns a (possibly nested) dict whose numeric
-        leaves are exported as gauges under ``prefix``."""
-        with self._lock:
-            self._collectors.append((prefix, fn))
-
-    def collect(self) -> List[_Metric]:
-        with self._lock:
-            return list(self._metrics.values())
-
-    def collector_values(self) -> Dict[str, Dict]:
-        with self._lock:
-            collectors = list(self._collectors)
-        out = {}
-        for prefix, fn in collectors:
-            try:
-                out[prefix] = fn()
-            except Exception:   # a dead collector must not kill export
-                continue
-        return out
-
-    def size(self) -> int:
-        with self._lock:
-            return len(self._metrics)
-
-    def clear(self):
-        with self._lock:
-            self._metrics.clear()
-            self._collectors.clear()
-
-
-REGISTRY = Registry()

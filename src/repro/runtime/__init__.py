@@ -5,6 +5,8 @@
 # cache.py     — process-wide ProgramCache (hit/miss/cold-compile stats,
 #                AOT serialization hook) + jit_program for host-driven
 #                single-network programs (NEL steps, baselines)
+# compiles.py  — the process's one jax.monitoring compile listener
+#                (backend_compiles, compile_s, runtime.compile spans)
 # specs.py     — generic spec builders (ensemble step/predict, map_step)
 # backends.py  — Runtime protocol: NelRuntime / CompiledRuntime
 # bucketing.py — power-of-two batch bucketing shared with serve/

@@ -100,7 +100,7 @@ class _BaseRuntime:
                     self.pd.store.per_device_bytes("params"),
                 "reshards": store_stats["device_puts"],
             },
-            # tracer + metric-registry state (repro.obs): is tracing on,
+            # tracer state (repro.obs): is tracing on,
             # how many spans recorded/buffered/dropped, ring capacity
             "obs": _obs_summary(),
         }

@@ -23,7 +23,11 @@ engine opened after a second engine on the same store compiles nothing.
 
 ``stats`` distinguishes hits (key present), misses (key absent), and
 cold_compiles (a program was actually built — misses served from an AOT
-``preload`` are not cold). ``aot_dump``/``preload`` are the ahead-of-time
+``preload`` are not cold). A cold compile only builds the jit wrapper;
+the trace and XLA compile run at the program's first call.
+``snapshot_stats()`` adds the process's ``backend_compiles`` and
+``compile_s`` (``runtime/compiles.py``), which count those, for every
+jit in the process. ``aot_dump``/``preload`` are the ahead-of-time
 serialization hook: programs export via ``jax.export`` to one file per
 cache key so a warm process can be seeded without recompiling.
 """
@@ -38,6 +42,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from ..core.store import Placement
 from ..obs import trace as _trace
+from . import compiles
 from .program import Program, ProgramSpec, abstract_key, lower
 
 
@@ -61,6 +66,7 @@ class ProgramCache:
         self.max_programs = max_programs
         self.stats = {"hits": 0, "misses": 0, "cold_compiles": 0,
                       "evictions": 0}
+        compiles.install()
 
     # -- key construction ----------------------------------------------------
     @staticmethod
@@ -149,7 +155,8 @@ class ProgramCache:
             s["programs"] = len(self._programs)
             total = s["hits"] + s["misses"]
             s["hit_rate"] = s["hits"] / total if total else 0.0
-            return s
+        s.update(compiles.snapshot())
+        return s
 
     def programs(self) -> List[Program]:
         """The resident programs, least recently used first."""

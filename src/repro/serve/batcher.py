@@ -277,6 +277,10 @@ class Generation:
     mutual_info: List[float] = field(default_factory=list)  # epistemic part
     finish_reason: str = "length"           # "eos" | "length"
     preemptions: int = 0
+    # obs.clock stamps: submitted, first admitted, each token on the host
+    t_enqueue: Optional[float] = None
+    t_admit: Optional[float] = None
+    token_times: List[float] = field(default_factory=list)
 
     @property
     def text_ids(self) -> List[int]:
@@ -292,7 +296,7 @@ class _Seq:
     sampling makes the replay exact)."""
     __slots__ = ("sid", "prompt", "max_new", "eos_id", "future", "generated",
                  "logprobs", "entropy", "mutual_info", "t_enqueue",
-                 "preemptions")
+                 "t_admit", "token_times", "preemptions")
 
     def __init__(self, sid: int, prompt: List[int], max_new: int,
                  eos_id: Optional[int], future: PFuture):
@@ -306,6 +310,8 @@ class _Seq:
         self.entropy: List[float] = []
         self.mutual_info: List[float] = []
         self.t_enqueue = clock.now()
+        self.t_admit: Optional[float] = None
+        self.token_times: List[float] = []
         self.preemptions = 0
 
     @property
@@ -325,7 +331,9 @@ class _Seq:
                           logprobs=self.logprobs, entropy=self.entropy,
                           mutual_info=self.mutual_info,
                           finish_reason=self.finish_reason() or "length",
-                          preemptions=self.preemptions)
+                          preemptions=self.preemptions,
+                          t_enqueue=self.t_enqueue, t_admit=self.t_admit,
+                          token_times=self.token_times)
 
 
 # store -> scheduler, consumed by runtime/backends.stats() (lazy import
@@ -363,6 +371,11 @@ class DecodeScheduler:
                along masked with seq_len -1);
       retire   rows hitting eos/max_new release pages and resolve their
                PFuture in the SAME iteration the row frees up.
+
+    Each phase is an obs span (``decode.admit``, ``decode.grow``,
+    ``decode.step`` with its pack / dispatch / sync, ``decode.emit`` for
+    append + retire), and ``stats`` keeps its host seconds whether
+    tracing is on or not (DESIGN.md §12).
 
     ``step_lock`` serializes steps against external store churn: hold it
     around ``pd.p_clone``/``p_kill`` so lifecycle ops never interleave
@@ -416,6 +429,12 @@ class DecodeScheduler:
             "steps": 0, "prefills": 0, "generated_tokens": 0,
             "active_row_steps": 0, "admission_blocked": 0,
             "h2d_transfers": 0, "errors": 0, "max_queue_depth": 0,
+            # host seconds by phase (obs.clock, always on): packing the
+            # step input, dispatching it, the device_get of its heads,
+            # appending/retiring after it; every prefill, and the replays
+            # among them (re-admissions after a preemption)
+            "pack_s": 0.0, "dispatch_s": 0.0, "sync_s": 0.0, "emit_s": 0.0,
+            "prefill_s": 0.0, "replay_prefill_s": 0.0, "replay_prefills": 0,
         }
         _DECODE_SCHEDULERS[id(engine.store)] = self
 
@@ -495,7 +514,8 @@ class DecodeScheduler:
                 self._fail_all(e)
 
     def _step(self):
-        self._admit()
+        with _trace.span("decode.admit", "decode"):
+            self._admit()
         active = [(i, s) for i, s in enumerate(self._rows) if s is not None]
         if not active:
             if self._waiting:     # admission blocked on a dry pool with
@@ -503,13 +523,15 @@ class DecodeScheduler:
             return
         # grow: every seated row needs the page holding the position its
         # next token writes; dry pool preempts youngest-first
-        for i, seq in active:
-            if self._rows[i] is seq:    # not preempted by an earlier row
-                self._ensure_page(seq)
+        with _trace.span("decode.grow", "decode"):
+            for i, seq in active:
+                if self._rows[i] is seq:    # not preempted by an earlier row
+                    self._ensure_page(seq)
         active = [(i, s) for i, s in enumerate(self._rows) if s is not None]
         if not active:
             return
         with _trace.span("decode.step", "decode", rows=len(active)):
+            t_pack = clock.now()
             self._packed[:, 0] = 0
             self._packed[:, 1] = -1
             self._packed[:, 2:] = 0
@@ -518,12 +540,38 @@ class DecodeScheduler:
                 self._packed[i, 1] = len(seq.all_tokens) - 1
                 self.pool.fill_block_row(seq.sid, self._packed[i, 2:])
             self.stats["h2d_transfers"] += 1
-            heads = jax.device_get(self.engine.decode_step(self._packed))
+            heads = self._launch(t_pack, self.engine.decode_step,
+                                 self._packed)
         self.stats["steps"] += 1
         self.stats["active_row_steps"] += len(active)
-        for i, seq in active:
-            self._append_token(seq, heads, i)
-            self._maybe_retire(i, seq)
+        t_emit = clock.now()
+        with _trace.span("decode.emit", "decode"):
+            for i, seq in active:
+                self._append_token(seq, heads, i)
+                self._maybe_retire(i, seq)
+        self.stats["emit_s"] += clock.now() - t_emit
+
+    def _launch(self, t_pack: float, fn, *args):
+        """Dispatch one packed step (``fn(*args)``) and bring its output
+        to the host. ``t_pack`` is when packing its input began. Adds the
+        pack / dispatch / sync phases to the counters and, with tracing
+        on, records them as ``decode.pack`` / ``decode.dispatch`` (the
+        program's own span nests inside) / ``decode.sync`` spans."""
+        t_disp = clock.now()
+        out = fn(*args)
+        t_sync = clock.now()
+        host = jax.device_get(out)
+        t_done = clock.now()
+        st = self.stats
+        st["pack_s"] += t_disp - t_pack
+        st["dispatch_s"] += t_sync - t_disp
+        st["sync_s"] += t_done - t_sync
+        tr = _trace.TRACER
+        if tr.enabled:
+            tr.record("decode.pack", "decode", t_pack, t_disp)
+            tr.record("decode.dispatch", "decode", t_disp, t_sync)
+            tr.record("decode.sync", "decode", t_sync, t_done)
+        return host
 
     def _admit(self):
         ps = self.pool.page_size
@@ -546,6 +594,8 @@ class DecodeScheduler:
                     return                    # backpressure: pool is dry
                 self._waiting.popleft()
                 self._cond.notify_all()       # wake backpressured submitters
+            if seq.t_admit is None:           # first admission: its wait in
+                seq.t_admit = clock.now()     # the queue ends, prefill starts
             try:
                 heads = self._prefill(seq, n_pf)
             except BaseException as e:
@@ -555,7 +605,7 @@ class DecodeScheduler:
                 continue
             self._rows[row] = seq
             self.stats["admitted"] += 1
-            _trace.instant("decode.admit", "decode", sid=seq.sid,
+            _trace.instant("decode.seat", "decode", sid=seq.sid,
                            replay=bool(seq.generated))
             if not seq.generated:
                 # the prefill head IS the first generated token; replays
@@ -586,6 +636,7 @@ class DecodeScheduler:
     def _prefill(self, seq: _Seq, n_pf: int):
         tokens = seq.all_tokens[:n_pf]
         bucket = self._prefill_bucket(n_pf)
+        t0 = clock.now()
         with _trace.span("decode.prefill", "decode", sid=seq.sid,
                          tokens=n_pf, bucket=bucket):
             buf = self._prefill_buf(bucket)
@@ -596,7 +647,19 @@ class DecodeScheduler:
             buf[-1] = n_pf
             self.stats["prefills"] += 1
             self.stats["h2d_transfers"] += 1
-            return jax.device_get(self.engine.prefill(buf))
+            heads = jax.device_get(self.engine.prefill(buf))
+        t1 = clock.now()
+        st = self.stats
+        st["prefill_s"] += t1 - t0
+        if seq.generated:
+            # a replay: the sequence was preempted and rebuilds its pages;
+            # its span encloses the prefill's
+            st["replay_prefills"] += 1
+            st["replay_prefill_s"] += t1 - t0
+            if _trace.TRACER.enabled:
+                _trace.TRACER.record("decode.replay", "decode", t0, t1,
+                                     {"sid": seq.sid, "tokens": n_pf})
+        return heads
 
     def _ensure_page(self, seq: _Seq, extra: int = 0) -> bool:
         """Make the page for ``seq``'s next write position resident —
@@ -608,7 +671,7 @@ class DecodeScheduler:
         while len(self.pool.pages_of(seq.sid)) < need:
             if self.pool.alloc(seq.sid,
                                need - len(self.pool.pages_of(seq.sid))):
-                _trace.instant("decode.grow", "decode", sid=seq.sid,
+                _trace.instant("decode.new_page", "decode", sid=seq.sid,
                                pages=need)
                 return True
             victim = max((s for s in self._rows if s is not None),
@@ -634,6 +697,7 @@ class DecodeScheduler:
         seq.logprobs.append(float(heads["logprob"][i]))
         seq.entropy.append(float(heads["entropy"][i]))
         seq.mutual_info.append(float(heads["mutual_info"][i]))
+        seq.token_times.append(clock.now())
         self.stats["generated_tokens"] += 1
 
     def _maybe_retire(self, row: int, seq: _Seq):

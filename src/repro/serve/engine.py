@@ -75,7 +75,8 @@ def _bma_reduce_heads(outs, placement: Placement, n: int, kind: str,
         # gets all members' outputs, then reduces locally (replicated)
         outs = jax.lax.with_sharding_constraint(
             outs, placement.replicated(outs))
-    return uncertainty.predictive_heads(outs, kind, mask), outs
+    with jax.named_scope("push.bma_heads"):
+        return uncertainty.predictive_heads(outs, kind, mask), outs
 
 
 class PredictiveEngine:

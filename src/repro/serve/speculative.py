@@ -49,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import precision as precision_mod
+from ..obs import clock
 from ..obs import trace as _trace
 from ..runtime import abstract_key, ident
 from ..runtime.specs import spec_draft_step, spec_verify
@@ -344,7 +345,8 @@ class SpeculativeDecodeScheduler(DecodeScheduler):
 
     def _step(self):
         import time
-        self._admit()
+        with _trace.span("decode.admit", "decode"):
+            self._admit()
         active = [(i, s) for i, s in enumerate(self._rows) if s is not None]
         if not active:
             if self._waiting:
@@ -354,12 +356,13 @@ class SpeculativeDecodeScheduler(DecodeScheduler):
         # len-1 .. len-2+k, verify writes one more; submit-time bounds
         # guarantee the window always fits a sequence's page cap
         plans: Dict[int, int] = {}
-        for i, seq in active:
-            if self._rows[i] is not seq:
-                continue
-            k_i = self._plan_k(seq)
-            if self._ensure_page(seq, extra=k_i):
-                plans[seq.sid] = k_i
+        with _trace.span("decode.grow", "decode"):
+            for i, seq in active:
+                if self._rows[i] is not seq:
+                    continue
+                k_i = self._plan_k(seq)
+                if self._ensure_page(seq, extra=k_i):
+                    plans[seq.sid] = k_i
         active = [(i, s) for i, s in enumerate(self._rows) if s is not None]
         if not active:
             return
@@ -371,6 +374,7 @@ class SpeculativeDecodeScheduler(DecodeScheduler):
                              slot=slot,
                              tokens=sum(plans.get(s.sid, 0)
                                         for _, s in active)):
+                t_pack = clock.now()
                 d = self._draft_packed
                 d[:, 0] = 0
                 d[:, 1] = -1
@@ -382,12 +386,13 @@ class SpeculativeDecodeScheduler(DecodeScheduler):
                     self.pool.fill_block_row(seq.sid, d[i, 3:])
                 self.stats["h2d_transfers"] += 1
                 drafts = np.asarray(
-                    jax.device_get(self.engine.draft_step(d, slot)))
+                    self._launch(t_pack, self.engine.draft_step, d, slot))
             self.spec_stats["draft_calls"] += 1
             self.spec_stats["drafted_tokens"] += int(
                 sum(plans.get(s.sid, 0) for _, s in active))
 
         with _trace.span("decode.verify", "decode", rows=len(active)):
+            t_pack = clock.now()
             v = self._verify_packed
             v[:] = 0
             v[:, self.w_max] = -1
@@ -400,47 +405,52 @@ class SpeculativeDecodeScheduler(DecodeScheduler):
                 v[i, self.w_max + 1] = k_i + 1
                 self.pool.fill_block_row(seq.sid, v[i, self.w_max + 2:])
             self.stats["h2d_transfers"] += 1
-            heads = jax.device_get(self.engine.verify_step(v))
+            heads = self._launch(t_pack, self.engine.verify_step, v)
         self.spec_stats["verify_calls"] += 1
         self.spec_stats["spec_steps"] += 1
         self.stats["steps"] += 1
         self.stats["active_row_steps"] += len(active)
 
-        for i, seq in active:
-            k_i = plans.get(seq.sid, 0)
-            bma = heads["token"][i]             # (W,) per-position argmax
-            # accept rule: position 0's argmax is always right (it
-            # conditions only on committed tokens); draft j survives iff
-            # it
-            # equals the BMA argmax at position j-1, and each surviving
-            # draft unlocks the argmax after it
-            m = 1
-            while m <= k_i and int(drafts[i, m - 1]) == int(bma[m - 1]):
-                m += 1
-            emitted = 0
-            for j in range(m):
-                self._append_window_token(seq, heads, i, j)
-                emitted += 1
-                if seq.finish_reason() == "eos":
-                    break
-            self.spec_stats["accepted_tokens"] += max(0, emitted - 1)
-            self._observe_acceptance(seq, k_i, m - 1)
-            # rollback: keep pages for the KV the accepted prefix needs
-            # (entries for all_tokens[:-1]); the rejected tail's pages
-            # come back page-granularly, its KV is position-masked dead
-            freed = self.pool.release_tail(seq.sid,
-                                           len(seq.all_tokens) - 1)
-            if freed:
-                self.spec_stats["rollback_pages"] += freed
-                _trace.instant("decode.rollback", "decode", sid=seq.sid,
-                               pages=freed)
-            self._maybe_retire(i, seq)
+        t_emit = clock.now()
+        with _trace.span("decode.emit", "decode"):
+            for i, seq in active:
+                k_i = plans.get(seq.sid, 0)
+                bma = heads["token"][i]         # (W,) per-position argmax
+                # accept rule: position 0's argmax is always right (it
+                # conditions only on committed tokens); draft j survives
+                # iff it equals the BMA argmax at position j-1, and each
+                # surviving draft unlocks the argmax after it
+                m = 1
+                while m <= k_i and \
+                        int(drafts[i, m - 1]) == int(bma[m - 1]):
+                    m += 1
+                emitted = 0
+                for j in range(m):
+                    self._append_window_token(seq, heads, i, j)
+                    emitted += 1
+                    if seq.finish_reason() == "eos":
+                        break
+                self.spec_stats["accepted_tokens"] += max(0, emitted - 1)
+                self._observe_acceptance(seq, k_i, m - 1)
+                # rollback: keep pages for the KV the accepted prefix
+                # needs (entries for all_tokens[:-1]); the rejected tail's
+                # pages come back page-granularly, its KV is
+                # position-masked dead
+                freed = self.pool.release_tail(seq.sid,
+                                               len(seq.all_tokens) - 1)
+                if freed:
+                    self.spec_stats["rollback_pages"] += freed
+                    _trace.instant("decode.rollback", "decode", sid=seq.sid,
+                                   pages=freed)
+                self._maybe_retire(i, seq)
+        self.stats["emit_s"] += clock.now() - t_emit
 
     def _append_window_token(self, seq: _Seq, heads, i: int, j: int):
         seq.generated.append(int(heads["token"][i][j]))
         seq.logprobs.append(float(heads["logprob"][i][j]))
         seq.entropy.append(float(heads["entropy"][i][j]))
         seq.mutual_info.append(float(heads["mutual_info"][i][j]))
+        seq.token_times.append(clock.now())
         self.stats["generated_tokens"] += 1
 
     # -- bookkeeping overrides ------------------------------------------------

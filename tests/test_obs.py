@@ -1,9 +1,11 @@
 """repro.obs: golden schema for every pd.stats() section (the keys are
 the repo's observability contract — renaming one breaks dashboards),
-tracer semantics (nesting, ring bound, disabled no-op), the typed metric
-registry, the Chrome/Perfetto + Prometheus exporters, per-Program cost
-attribution, and the latency-percentile dedup regression (service stats
-keys byte-identical after the Histogram collapse)."""
+tracer semantics (nesting, ring bound, disabled no-op), the latency
+Histogram, the Chrome/Perfetto + Prometheus exporters, per-Program cost
+attribution, the latency-percentile dedup regression (service stats
+keys byte-identical after the Histogram collapse), and the program's own
+accounting of where the host's time goes: decode-step phases, replays,
+token stamps, compiles, the fused training call's edges."""
 import json
 import threading
 
@@ -62,14 +64,15 @@ GOLDEN = {
               "device_puts", "checkouts", "mask_invalidations",
               "capacity_growths", "slot_clones"},
     "program_cache": {"hits", "misses", "cold_compiles", "evictions",
-                      "programs", "hit_rate"},
+                      "programs", "hit_rate", "backend_compiles",
+                      "compile_s"},
     "lifecycle": {"capacity", "live", "free_slots", "generation",
                   "mask_invalidations", "capacity_growths", "clones",
                   "kills", "rebalances"},
     "placement": {"mesh_shape", "mode", "particle_axis", "model_axis",
                   "model_axis_size", "per_device_param_bytes", "reshards"},
     "obs": {"tracing_enabled", "spans_recorded", "spans_buffered",
-            "spans_dropped", "ring", "clock", "metrics"},
+            "spans_dropped", "ring", "clock"},
 }
 
 
@@ -150,7 +153,8 @@ def test_decode_stats_golden_schema():
                 "admission_blocked", "h2d_transfers", "errors",
                 "max_queue_depth", "queue_depth", "active_seqs",
                 "max_active", "row_occupancy", "pool", "kv_pages",
-                "speculative"}
+                "speculative", "pack_s", "dispatch_s", "sync_s", "emit_s",
+                "prefill_s", "replay_prefill_s", "replay_prefills"}
             assert set(dec["kv_pages"]) == {"key", "dtypes",
                                             "per_device_bytes"}
             assert dec["kv_pages"]["key"] == "kv_pages"
@@ -297,34 +301,18 @@ def test_bdl_epoch_spans():
 
 
 # ---------------------------------------------------------------------------
-# metric registry
+# latency histogram
 # ---------------------------------------------------------------------------
 
 def test_counter_gauge_histogram():
-    reg = metrics.Registry()
-    c = reg.counter("requests", route="predict")
-    c.inc()
-    c.inc(4)
-    assert c.value == 5
-    assert reg.counter("requests", route="predict") is c   # get-or-create
-    assert reg.counter("requests", route="decode") is not c
-
-    g = reg.gauge("depth")
-    g.set(7.5)
-    assert g.value == 7.5
-    g.set_fn(lambda: 42)
-    assert g.value == 42
-
-    h = reg.histogram("lat", ring=4)
+    h = metrics.Histogram("lat", ring=4)
     for v in (1.0, 2.0, 3.0, 4.0, 5.0):
         h.observe(v)
     assert h.count == 5 and h.sum == 15.0
     assert h.values() == [2.0, 3.0, 4.0, 5.0]       # ring dropped 1.0
     snap = h.snapshot()
     assert snap["count"] == 5 and snap["p50"] == 3.5
-    with pytest.raises(TypeError):
-        reg.gauge("lat")                             # kind clash
-    assert reg.size() == 4
+    assert h.percentile(100) == 5.0
 
 
 def test_percentile_matches_numpy_and_empty():
@@ -333,14 +321,6 @@ def test_percentile_matches_numpy_and_empty():
         assert metrics.percentile(xs, q) == pytest.approx(
             float(np.percentile(np.asarray(xs), q)))
     assert metrics.percentile([], 99) == 0.0
-
-
-def test_registry_collectors():
-    reg = metrics.Registry()
-    reg.register_collector("store", lambda: {"live": 3, "nested": {"a": 1}})
-    reg.register_collector("dead", lambda: 1 / 0)    # must not kill export
-    vals = reg.collector_values()
-    assert vals == {"store": {"live": 3, "nested": {"a": 1}}}
 
 
 # ---------------------------------------------------------------------------
@@ -383,24 +363,28 @@ def test_clock_to_us():
 
 
 def test_prometheus_text():
-    reg = metrics.Registry()
-    reg.counter("reqs", route="a").inc(3)
-    reg.gauge("depth").set(2)
-    h = reg.histogram("lat_s")
-    for v in (0.1, 0.2, 0.3):
-        h.observe(v)
-    reg.register_collector("store", lambda: {"live": 4})
+    """The exporter renders the pd.stats() snapshot it is given: every
+    numeric leaf (nested dicts, lists, bools) a gauge, names sanitized,
+    strings and None dropped."""
+    pd = _pd()
+    try:
+        pd.p_predict({"x": jax.random.normal(jax.random.PRNGKey(0), (4, 3))})
+        st = pd.stats()
+        text = export.prometheus_text(st)
+        hits = st["program_cache"]["hits"]
+        assert "# TYPE repro_program_cache_hits gauge" in text
+        assert f"repro_program_cache_hits {float(hits)}" in text
+        assert "repro_program_cache_compile_s " in text
+        assert "repro_lifecycle_live 3.0" in text
+        assert "repro_obs_tracing_enabled 0.0" in text      # a bool
+        assert "perf_counter" not in text                   # a string
+    finally:
+        pd.cleanup()
     text = export.prometheus_text(
-        reg, extra={"serve": {"p99 (ms)": 1.5, "name": "drop-me"}})
-    assert "# TYPE repro_reqs counter" in text
-    assert 'repro_reqs{route="a"} 3.0' in text
-    assert "# TYPE repro_depth gauge" in text
-    assert "# TYPE repro_lat_s summary" in text
-    assert 'repro_lat_s{quantile="0.5"} 0.2' in text
-    assert "repro_lat_s_count 3" in text
-    assert "repro_store_live 4.0" in text
+        {"serve": {"p99 (ms)": 1.5, "name": "drop-me", "gaps": [2, None]}})
     assert "repro_serve_p99__ms_ 1.5" in text       # sanitized name
-    assert "drop-me" not in text                    # strings are dropped
+    assert "repro_serve_gaps_0 2.0" in text
+    assert "drop-me" not in text and "gaps_1" not in text
 
 
 # ---------------------------------------------------------------------------

@@ -55,10 +55,16 @@ def test_cache_hit_miss_cold_stats():
     spec = _double_spec()
     st = jnp.ones((2, 3))
     b = jnp.ones((4,))
+    before = cache.snapshot_stats()
     cache.run(spec, st, b)
-    assert cache.snapshot_stats() == {
+    s = cache.snapshot_stats()
+    comp = {k: s.pop(k) for k in ("backend_compiles", "compile_s")}
+    assert s == {
         "hits": 0, "misses": 1, "cold_compiles": 1, "evictions": 0,
         "programs": 1, "hit_rate": 0.0}
+    # the process's compile listener heard the first call's XLA compile
+    assert comp["backend_compiles"] >= before["backend_compiles"] + 1
+    assert comp["compile_s"] > before["compile_s"]
     cache.run(spec, st, b)
     s = cache.snapshot_stats()
     assert s["hits"] == 1 and s["cold_compiles"] == 1
