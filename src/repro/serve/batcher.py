@@ -435,6 +435,9 @@ class DecodeScheduler:
             # among them (re-admissions after a preemption)
             "pack_s": 0.0, "dispatch_s": 0.0, "sync_s": 0.0, "emit_s": 0.0,
             "prefill_s": 0.0, "replay_prefill_s": 0.0, "replay_prefills": 0,
+            # page slots the paged attention kernel walks (rows x n_pmax a
+            # step) and the live ones among them, which alone cost a copy
+            "kv_pages_live": 0, "kv_page_slots": 0,
         }
         _DECODE_SCHEDULERS[id(engine.store)] = self
 
@@ -539,6 +542,7 @@ class DecodeScheduler:
                 self._packed[i, 0] = seq.all_tokens[-1]
                 self._packed[i, 1] = len(seq.all_tokens) - 1
                 self.pool.fill_block_row(seq.sid, self._packed[i, 2:])
+            self._count_kv_pages(self._packed[:, 1])
             self.stats["h2d_transfers"] += 1
             heads = self._launch(t_pack, self.engine.decode_step,
                                  self._packed)
@@ -550,6 +554,15 @@ class DecodeScheduler:
                 self._append_token(seq, heads, i)
                 self._maybe_retire(i, seq)
         self.stats["emit_s"] += clock.now() - t_emit
+
+    def _count_kv_pages(self, last_pos: np.ndarray):
+        """Add one step's walk to ``kv_pages_live`` / ``kv_page_slots``:
+        ``last_pos`` holds each row's last attended position (-1 for an
+        inactive row), so a row's live pages are ``last_pos // page_size
+        + 1``."""
+        live = last_pos[last_pos >= 0] // self.pool.page_size + 1
+        self.stats["kv_pages_live"] += int(live.sum())
+        self.stats["kv_page_slots"] += self.max_active * self.n_pmax
 
     def _launch(self, t_pack: float, fn, *args):
         """Dispatch one packed step (``fn(*args)``) and bring its output

@@ -404,6 +404,9 @@ class SpeculativeDecodeScheduler(DecodeScheduler):
                 v[i, self.w_max] = len(seq.all_tokens) - 1
                 v[i, self.w_max + 1] = k_i + 1
                 self.pool.fill_block_row(seq.sid, v[i, self.w_max + 2:])
+            # the window's last query attends through position
+            # start + win_len - 1 (inactive rows: -1 + 0 - 1, still < 0)
+            self._count_kv_pages(v[:, self.w_max] + v[:, self.w_max + 1] - 1)
             self.stats["h2d_transfers"] += 1
             heads = self._launch(t_pack, self.engine.verify_step, v)
         self.spec_stats["verify_calls"] += 1

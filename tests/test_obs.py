@@ -154,7 +154,8 @@ def test_decode_stats_golden_schema():
                 "max_queue_depth", "queue_depth", "active_seqs",
                 "max_active", "row_occupancy", "pool", "kv_pages",
                 "speculative", "pack_s", "dispatch_s", "sync_s", "emit_s",
-                "prefill_s", "replay_prefill_s", "replay_prefills"}
+                "prefill_s", "replay_prefill_s", "replay_prefills",
+                "kv_pages_live", "kv_page_slots"}
             assert set(dec["kv_pages"]) == {"key", "dtypes",
                                             "per_device_bytes"}
             assert dec["kv_pages"]["key"] == "kv_pages"

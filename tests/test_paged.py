@@ -97,22 +97,29 @@ def test_page_pool_release_tail_invariants():
 # paged decode kernel vs oracle and vs the dense decode kernel
 # ---------------------------------------------------------------------------
 
-def _paged_case(seed, B, H, KVH, hd, ps, n_pmax, NP, lens):
+def _paged_case(seed, B, H, KVH, hd, ps, n_pmax, NP, lens, dirty=False):
     """Random pages + block tables with the PagePool conventions: rows
     with len -1 inactive, unused block-table entries 0, tail slots of the
-    last page holding stale garbage from 'previous owners'."""
+    last page holding stale garbage from 'previous owners'. ``dirty``:
+    unused entries hold in-range page ids instead, and every page that no
+    row owns is NaN, so reading one would show in the output."""
     rng = np.random.default_rng(seed)
     q = jnp.asarray(rng.standard_normal((B, 1, H, hd)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((NP, ps, KVH, hd)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((NP, ps, KVH, hd)), jnp.float32)
-    bt = np.zeros((B, n_pmax), np.int32)
+    k = rng.standard_normal((NP, ps, KVH, hd)).astype(np.float32)
+    v = rng.standard_normal((NP, ps, KVH, hd)).astype(np.float32)
+    bt = rng.integers(0, NP, (B, n_pmax)).astype(np.int32) if dirty \
+        else np.zeros((B, n_pmax), np.int32)
     free = list(rng.permutation(NP))
     for b, sl in enumerate(lens):
         if sl < 0:
             continue
         for i in range(sl // ps + 1):
             bt[b, i] = free.pop()
-    return q, k, v, jnp.asarray(bt), jnp.asarray(lens, jnp.int32)
+    if dirty:
+        k[free] = np.nan
+        v[free] = np.nan
+    return (q, jnp.asarray(k), jnp.asarray(v), jnp.asarray(bt),
+            jnp.asarray(lens, jnp.int32))
 
 
 @pytest.mark.parametrize("B,H,KVH,hd,ps,n_pmax,lens", [
@@ -120,13 +127,22 @@ def _paged_case(seed, B, H, KVH, hd, ps, n_pmax, NP, lens):
     (3, 8, 1, 16, 8, 6, [0, 33, 21]),      # MQA, single-token row
     (2, 4, 4, 8, 16, 3, [-1, 40]),         # MHA + an inactive row
     (4, 6, 3, 64, 32, 2, [5, -1, 63, 31]), # group=2, mixed ragged
+    (3, 16, 16, 64, 16, 12, [20, 5, 40]),  # MHA at hd 64, n_pmax >> live
+    (3, 8, 2, 32, 16, 4, [15, 16, 17]),    # page boundaries ps-1, ps, ps+1
+    (4, 16, 16, 64, 128, 3, [-1, 127, -1, 129]),  # pages of 128; leading,
+                                                  # inner inactive rows
+    (2, 4, 2, 16, 8, 3, [-1, -1]),         # no live page at all
 ])
 def test_paged_kernel_vs_oracle(B, H, KVH, hd, ps, n_pmax, lens):
+    """Unused block-table entries hold in-range garbage and every page no
+    row owns is NaN: the kernel reads live pages only. The oracle, which
+    gathers every slot, reads the same pool with the NaN zeroed."""
     NP = B * n_pmax + 2
     q, k, v, bt, sl = _paged_case(B * 7 + ps, B, H, KVH, hd, ps, n_pmax,
-                                  NP, lens)
+                                  NP, lens, dirty=True)
     out = ops.paged_decode_attention(q, k, v, bt, sl)
-    want = ref.paged_decode_attention(q, k, v, bt, sl)
+    want = ref.paged_decode_attention(q, jnp.nan_to_num(k),
+                                      jnp.nan_to_num(v), bt, sl)
     assert float(jnp.abs(out - want).max()) < 1e-4
     for b, L in enumerate(lens):           # inactive rows exactly zero
         if L < 0:
@@ -326,6 +342,46 @@ def test_scheduler_eos_and_request_validation():
                 svc.generate([1] * 100, max_new=100)
         finally:
             svc.close()
+
+
+def test_scheduler_counts_live_kv_pages_per_step():
+    """kv_pages_live / kv_page_slots grow, step by step, by the page
+    slots the paged kernel walks: each active row's live pages from its
+    seq_len, over max_active rows x n_pmax slots."""
+    cfg = _tiny_cfg()
+    rng = np.random.default_rng(2)
+    prompts = [list(rng.integers(1, cfg.vocab_size, n)) for n in (3, 9, 5)]
+    with _lm_pd(cfg, n=1) as pd:
+        svc = serve_decode(pd, cfg, num_pages=16, page_size=4,
+                           max_active=4, max_seq_pages=5, warmup=False)
+        sched = svc.scheduler
+        seen = []
+        step = sched.engine.decode_step
+
+        def spy(packed):
+            seen.append((packed.copy(), sched.stats["kv_pages_live"],
+                         sched.stats["kv_page_slots"]))
+            return step(packed)
+
+        sched.engine.decode_step = spy
+        try:
+            for h in [svc.generate_async(p, max_new=8) for p in prompts]:
+                h.result(300)
+            st = pd.stats()["decode"]
+        finally:
+            svc.close()
+    assert len(seen) == st["steps"] > 0
+    live = slots = 0
+    for packed, live_now, slots_now in seen:
+        sl, bt = packed[:, 1], packed[:, 2:]
+        pages = [int(p) for b in np.flatnonzero(sl >= 0)
+                 for p in bt[b, :sl[b] // 4 + 1]]
+        assert len(set(pages)) == len(pages)     # one owner per live page
+        assert live_now - live == len(pages)
+        assert slots_now - slots == 4 * 5
+        live, slots = live_now, slots_now
+    assert (st["kv_pages_live"], st["kv_page_slots"]) == (live, slots)
+    assert 0 < live < slots
 
 
 @pytest.mark.parametrize("warmed,n_pf,want", [
